@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 from .redas_gemm import VMEM_BYTES, default_blocks, vmem_bytes
 
 
@@ -80,7 +79,7 @@ def grouped_matmul(x: jax.Array, w: jax.Array, *, bc: int | None = None,
         out_specs=pl.BlockSpec((1, bc, bf), lambda ee, i, j, k: (ee, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, cp, fp), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -12,15 +12,15 @@ provides the `paged_attention` op in both guises:
                               (same einsums, same masking) so paged vs
                               contiguous greedy decode is bit-identical
                               — the parity oracle the tests lean on.
-  paged_attention_tpu         Pallas kernel, grid (B, KV, n_bt): the
+  paged_attention_tpu         Pallas kernel, grid (B, n_bt): the
                               block table and per-slot kv_len ride the
                               scalar-prefetch lane and each grid step's
                               k/v BlockSpec index map dereferences
-                              bt[b, i] directly — pages stream
+                              bt[b, i] directly — a slot's pages stream
                               HBM->VMEM exactly once, no gathered copy
-                              of the cache ever materializes.  Online-
-                              softmax scratch carries (m, l, acc)
-                              across the page sweep, flash-style.
+                              of the cache ever materializes.
+                              Online-softmax scratch carries (m, l,
+                              acc) across the page sweep, flash-style.
 
 int8 composition (PR 5 codec): per-row scales page with their rows —
 `k_scale_pages`/`v_scale_pages` pools `(P, page, KV)` are indexed by
@@ -46,8 +46,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from ._compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -94,12 +92,12 @@ def paged_attention_reference(q: jax.Array, k_pages: jax.Array,
 
 
 def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest, scale: float,
-            page: int, n_bt: int, quantized: bool):
+            page: int, kv: int, g: int, n_bt: int, quantized: bool):
     if quantized:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
-    b, i = pl.program_id(0), pl.program_id(2)
+    b, i = pl.program_id(0), pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
@@ -107,37 +105,44 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest, scale: float,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale             # (G, d)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)               # (page, d)
+    # One page of every KV head arrives as (page * KV, d) rows; row
+    # c holds position c // KV of KV head c % KV, and query head r reads
+    # KV head r // G.  The kernel scores every (query head, row) pair
+    # and masks the pairs of different heads, so no sublane slice of the
+    # page is ever taken.
+    q = q_ref[0].astype(jnp.float32) * scale                # (H, d)
+    k = k_ref[0].astype(jnp.float32)                        # (page*KV, d)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (G, page)
+                            preferred_element_type=jnp.float32)
     if quantized:
-        s = s * ks_ref[0, :, 0].astype(jnp.float32)[None, :]
+        s = s * ks_ref[0].astype(jnp.float32)               # (1, page*KV)
 
-    g = q.shape[0]
-    pos = i * page + jax.lax.broadcasted_iota(jnp.int32, (g, page), 1)
-    s = jnp.where(pos < len_ref[b], s, NEG_INF)
+    shape = (q.shape[0], page * kv)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    pos = i * page + col // kv
+    s = jnp.where((col % kv == row // g) & (pos < len_ref[b]), s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
+    m_prev = m_ref[...]                                     # (H, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
     # When every position so far is masked (kv_len == 0), m_new is still
     # NEG_INF and exp(s - m_new) would be exp(0) = 1 — guard so fully
     # masked rows contribute an exact 0 instead of averaging page-0 v.
     dead = m_new == NEG_INF
-    p = jnp.where(dead[:, None], 0.0, jnp.exp(s - m_new[:, None]))
+    p = jnp.where(dead, 0.0, jnp.exp(s - m_new))
     corr = jnp.where(dead, 0.0, jnp.exp(m_prev - m_new))
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
+    l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
     m_ref[...] = m_new
     if quantized:
-        p = p * vs_ref[0, :, 0].astype(jnp.float32)[None, :]
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-        p, v_ref[0, :, 0, :].astype(jnp.float32), (((1,), (0,)), ((), ())),
+        p = p * vs_ref[0].astype(jnp.float32)
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+        p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     @pl.when(i == n_bt - 1)
     def _flush():
         l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -161,44 +166,50 @@ def paged_attention_tpu(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     n_bt = block_tables.shape[1]
     quantized = k_scale is not None
 
-    qr = q.reshape(b, kv, g, d)  # head h = kv_idx * g + g_idx, layers.py order
+    # head r = kv_idx * g + g_idx (layers.py order) reads KV head r // g.
+    # k/v blocks cover one page of the whole KV axis, flattened to
+    # (page * KV, d) rows (a free row-major reshape of the pool), and
+    # the scale blocks to (1, page * KV): the block's last two dims then
+    # equal the array's, which the TPU tiling rule accepts for any KV.
+    # One grid step serves every head of a slot, so each page streams
+    # HBM->VMEM once; the kernel pairs heads with their rows by masking.
+    def page_idx(b_, i_, bt, ln):
+        return (jnp.maximum(bt[b_, i_], 0), 0, 0)
 
-    def page_idx(b_, h_, i_, bt, ln):
-        return (jnp.maximum(bt[b_, i_], 0), 0, h_, 0)
-
-    def scale_idx(b_, h_, i_, bt, ln):
-        return (jnp.maximum(bt[b_, i_], 0), 0, h_)
+    def slot_idx(b_, i_, bt, ln):
+        return (b_, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda b_, h_, i_, bt, ln: (b_, h_, 0, 0)),
-        pl.BlockSpec((1, page, 1, d), page_idx),
-        pl.BlockSpec((1, page, 1, d), page_idx),
+        pl.BlockSpec((1, h, d), slot_idx),
+        pl.BlockSpec((1, page * kv, d), page_idx),
+        pl.BlockSpec((1, page * kv, d), page_idx),
     ]
-    args = [qr, k_pages, v_pages]
+    args = [q.reshape(b, h, d), k_pages.reshape(n_pool, page * kv, d),
+            v_pages.reshape(n_pool, page * kv, d)]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page, 1), scale_idx),
-                     pl.BlockSpec((1, page, 1), scale_idx)]
-        args += [k_scale, v_scale]
+        in_specs += [pl.BlockSpec((1, 1, page * kv), page_idx),
+                     pl.BlockSpec((1, 1, page * kv), page_idx)]
+        args += [k_scale.reshape(n_pool, 1, page * kv),
+                 v_scale.reshape(n_pool, 1, page * kv)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kv, n_bt),
+        grid=(b, n_bt),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda b_, h_, i_, bt, ln: (b_, h_, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, d), slot_idx),
         scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),      # running max
-            pltpu.VMEM((g,), jnp.float32),      # running denominator
-            pltpu.VMEM((g, d), jnp.float32),    # output accumulator
+            pltpu.VMEM((h, 1), jnp.float32),    # running max
+            pltpu.VMEM((h, 1), jnp.float32),    # running denominator
+            pltpu.VMEM((h, d), jnp.float32),    # output accumulator
         ],
     )
     out = pl.pallas_call(
         functools.partial(_kernel, scale=1.0 / math.sqrt(d), page=page,
-                          n_bt=n_bt, quantized=quantized),
+                          kv=kv, g=g, n_bt=n_bt, quantized=quantized),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kv, g, d), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), kv_len.astype(jnp.int32), *args)
     return out.reshape(b, sq, h, d)

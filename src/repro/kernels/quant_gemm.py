@@ -41,15 +41,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU compiler params are optional off-TPU (interpret mode ignores them)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
+from repro.engine.backends import auto_interpret
 from repro.quant.quantize import kv_quantize, quantize
 
-from ._compat import CompilerParams
 from .redas_gemm import VMEM_BYTES, round_up
 
 # int8 VREG tiling floor: (sublane, lane) = (32, 128) — four times the
@@ -143,8 +139,6 @@ def gemm_int8(a_q: jax.Array, b_q: jax.Array, *, bm: int, bk: int, bn: int,
             f"int8 blocks ({bm},{bk},{bn}) must be multiples of "
             f"({INT8_SUBLANE}, {LANE}) (int8 VREG tiling floor)")
     gm, gk, gn = m // bm, k // bk, n // bn
-    params = (CompilerParams(dimension_semantics=("arbitrary",) * 3)
-              if CompilerParams is not None else None)
     return pl.pallas_call(
         functools.partial(_int8_os_kernel, n_k=gk),
         grid=(gm, gn, gk),
@@ -153,7 +147,8 @@ def gemm_int8(a_q: jax.Array, b_q: jax.Array, *, bm: int, bk: int, bn: int,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3),
         interpret=interpret,
     )(a_q, b_q)
 
@@ -292,10 +287,6 @@ def _diff_quant_gemm_w8(bm, bk, bn, interpret, use_pallas, out_dtype):
 # ---------------------------------------------------------------------------
 
 
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _blocks(decision) -> tuple[int, int, int]:
     return align_int8_blocks(decision.bm, decision.bk, decision.bn)
 
@@ -303,7 +294,7 @@ def _blocks(decision) -> tuple[int, int, int]:
 def _gemm_backend(use_pallas: bool):
     def run(decision, a, b, *, out_dtype=None):
         bm, bk, bn = _blocks(decision)
-        fn = _diff_quant_gemm(bm, bk, bn, _auto_interpret(), use_pallas,
+        fn = _diff_quant_gemm(bm, bk, bn, auto_interpret(None), use_pallas,
                               out_dtype)
         return fn(a, b)
     return run
@@ -312,7 +303,7 @@ def _gemm_backend(use_pallas: bool):
 def _gemm_w8_backend(use_pallas: bool):
     def run(decision, a, w_q, w_scale, *, out_dtype=None):
         bm, bk, bn = _blocks(decision)
-        fn = _diff_quant_gemm_w8(bm, bk, bn, _auto_interpret(), use_pallas,
+        fn = _diff_quant_gemm_w8(bm, bk, bn, auto_interpret(None), use_pallas,
                                  out_dtype)
         return fn(a, w_q, w_scale)
     return run
@@ -324,7 +315,7 @@ def _grouped_backend(use_pallas: bool):
         path.  E is static, so the trace-time loop stays O(E) kernels —
         same posture as the float grouped kernel's per-expert grid."""
         bm, bk, bn = _blocks(decision)
-        fn = _diff_quant_gemm(bm, bk, bn, _auto_interpret(), use_pallas,
+        fn = _diff_quant_gemm(bm, bk, bn, auto_interpret(None), use_pallas,
                               out_dtype or x.dtype)
         outs = [fn(x[e], w[e]) for e in range(x.shape[0])]
         return jnp.stack(outs, axis=0)

@@ -46,13 +46,7 @@ from typing import Literal
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU compiler params are optional off-TPU (interpret mode ignores them)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
-from ._compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 DataflowName = Literal["os", "ws", "is"]
 
@@ -128,10 +122,8 @@ def _streaming_kernel(a_ref, b_ref, acc_ref, o_ref):
 
 
 def _compiler_params(n_axes: int):
-    if CompilerParams is None:
-        return None
     # Revisited output blocks require sequential ("arbitrary") grid axes.
-    return CompilerParams(dimension_semantics=("arbitrary",) * n_axes)
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary",) * n_axes)
 
 
 @functools.partial(

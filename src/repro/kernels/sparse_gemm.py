@@ -50,13 +50,10 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU compiler params are optional off-TPU (interpret mode ignores them)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from repro.engine.backends import _diff_gemm, auto_interpret
 
-from ._compat import CompilerParams
 from .redas_gemm import SUBLANE, VMEM_BYTES, round_up
 
 LANE = 128
@@ -98,11 +95,13 @@ def _scatter_dense(values, indices, n_keep: int, m_group: int):
     """Expand compressed (K_c, N) storage to the dense (K_c//N*M, N)
     tile: a one-hot sum over the in-group offset, unrolled statically
     over the group size.  Shared verbatim by the Pallas kernel body and
-    the XLA reference so the two construct bit-identical tiles."""
+    the XLA reference so the two construct bit-identical tiles.  The
+    offset compare runs in int32: the TPU vector unit has no int8
+    comparison."""
     k_c, bn = values.shape
     groups = k_c // n_keep
     v3 = values.reshape(groups, n_keep, bn)
-    i3 = indices.reshape(groups, n_keep, bn)
+    i3 = indices.reshape(groups, n_keep, bn).astype(jnp.int32)
     planes = [jnp.sum(jnp.where(i3 == off, v3, 0.0), axis=1)
               for off in range(m_group)]
     return jnp.stack(planes, axis=1).reshape(groups * m_group, bn)
@@ -158,8 +157,6 @@ def gemm_sparse(a: jax.Array, values: jax.Array, indices: jax.Array, *,
             f"({SUBLANE}, {_bk_unit(m_group)}, {LANE})")
     gm, gk, gn = m // bm, k // bk, n // bn
     bk_c = bk * n_keep // m_group
-    params = (CompilerParams(dimension_semantics=("arbitrary",) * 3)
-              if CompilerParams is not None else None)
     return pl.pallas_call(
         functools.partial(_sparse_os_kernel, n_k=gk, n_keep=n_keep,
                           m_group=m_group),
@@ -170,7 +167,8 @@ def gemm_sparse(a: jax.Array, values: jax.Array, indices: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3),
         interpret=interpret,
     )(a, values, indices)
 
@@ -321,18 +319,14 @@ def _diff_sparse_gemm_q(n_keep, m_group, interpret, use_pallas, out_dtype):
 # ---------------------------------------------------------------------------
 
 
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _sparse_backend(use_pallas: bool):
     def run(decision, a, values, indices, scale=None, *, n_keep=2,
             m_group=4, out_dtype=None):
         if scale is not None:
-            fn = _diff_sparse_gemm_q(n_keep, m_group, _auto_interpret(),
+            fn = _diff_sparse_gemm_q(n_keep, m_group, auto_interpret(None),
                                      use_pallas, out_dtype)
             return fn(a, values, indices, scale)
-        fn = _diff_sparse_gemm(n_keep, m_group, _auto_interpret(),
+        fn = _diff_sparse_gemm(n_keep, m_group, auto_interpret(None),
                                use_pallas, out_dtype)
         return fn(a, values, indices)
     return run
@@ -344,10 +338,8 @@ def _dense_gemm_backend(use_pallas: bool):
     still dispatch somewhere."""
     def run(decision, a, b, *, out_dtype=None):
         if use_pallas:
-            from repro.engine.backends import _diff_gemm  # lazy: avoids cycle
-
             fn = _diff_gemm(decision.dataflow, decision.bm, decision.bk,
-                            decision.bn, _auto_interpret(), out_dtype)
+                            decision.bn, auto_interpret(None), out_dtype)
             return fn(a, b)
         return _float_gemm(a, b, use_pallas=False, interpret=False,
                            out_dtype=out_dtype or a.dtype)

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape)
 cell on the production meshes, prove memory/sharding coherence, and dump
@@ -13,9 +14,11 @@ Single-cell mode does the work in-process; --all orchestrates one
 subprocess per cell (isolating XLA compile memory and letting a bad cell
 fail alone) and writes runs/dryrun/<mesh>/<arch>__<shape>.json.
 
-NOTE the XLA_FLAGS line above runs before any jax import: the dry-run
-(and only the dry-run) needs 512 placeholder host devices so
-jax.make_mesh can build the (2, 16, 16) production mesh.
+NOTE the two environment lines above run before any jax import: the
+dry-run (and only the dry-run) needs 512 placeholder host devices so
+launch.mesh can build the (2, 16, 16) production mesh, and it pins itself
+and the per-cell children (which inherit the environment) to the CPU,
+so that on a machine with a TPU none of them takes the chip.
 """
 
 import argparse

@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.configs import ARCH_NAMES, get_config
 from repro.dist import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.models import transformer as T
 from repro.serve_lib import serve as serve_lib
@@ -47,6 +48,15 @@ def parse_trace(spec: str) -> list[tuple[int, int]]:
     if not out:
         raise ValueError(f"empty trace spec {spec!r}")
     return out
+
+
+def init_serving_params(seed: int, cfg, dtype):
+    """Random params from `seed`, made directly in `dtype` inside one
+    jit, so no float32 copy of the whole model is ever live on the
+    device (at qwen2-1.5b's widths that copy alone is ~7 GB)."""
+    init = jax.jit(lambda key: jax.tree.map(
+        lambda p: p.astype(dtype), T.init_params(key, cfg)))
+    return init(jax.random.PRNGKey(seed))
 
 
 def _run_trace(params, cfg, scfg, args, trace) -> dict:
@@ -182,10 +192,10 @@ def main(argv=None) -> dict:
         draft=args.draft if args.speculate else None,
         prefill_chunk=args.prefill_chunk)
     mesh = make_test_mesh()
+    enable_compile_cache()
 
     with mesh, shd.use_mesh(mesh):
-        params = T.init_params(jax.random.PRNGKey(args.seed), cfg)
-        params = jax.tree.map(lambda p: p.astype(dtype), params)
+        params = init_serving_params(args.seed, cfg, dtype)
         if args.sparsity:
             from repro.sparse import parse_sparsity, prune_params
             n, m = parse_sparsity(args.sparsity)

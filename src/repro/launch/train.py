@@ -3,11 +3,13 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b --smoke \
         --steps 200 --batch 8 --seq 128 --ckpt-dir runs/ckpt --resume auto
 
-Production posture on real hardware: the same entry point under
-`jax.distributed.initialize()` — the mesh comes from launch.mesh, state
-sharding from dist.sharding, checkpoints reshard on restore so the run
-survives pod-count changes (elastic).  On this CPU host it trains the
-reduced configs end-to-end (examples/train_tiny_lm.py drives it).
+`--mesh DATAxMODEL` (default 1x1) names the (data, model) mesh over the
+devices present, e.g. `--mesh 2x2` on a four-chip host: the train state
+is born sharded on it (TP + FSDP rule table, dist.sharding) and
+checkpoints reshard on restore, so a run survives mesh-shape changes
+(elastic).  Across hosts the same entry point runs under
+`jax.distributed.initialize()`.  On a CPU host it trains the reduced
+configs end-to-end (examples/train_tiny_lm.py drives it).
 """
 
 from __future__ import annotations
@@ -22,10 +24,29 @@ from repro.checkpoint.checkpoint import Checkpointer
 from repro.configs import ARCH_NAMES, get_config
 from repro.data.pipeline import DataConfig, make_source
 from repro.dist import reshard, sharding as shd
-from repro.launch.mesh import make_test_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh, parse_mesh
 from repro.optim.adamw import AdamWConfig
 from repro.optim.schedule import linear_warmup_cosine
 from repro.train_lib import train as train_lib
+
+
+def build_train(cfg, tcfg: train_lib.TrainConfig, mesh, seed: int):
+    """(init, state_shardings, step) for `cfg` on `mesh`.  `init()`
+    makes the train state from `seed` directly in its shards (one jit
+    with out_shardings), so no device ever holds the whole state;
+    `step(state, batch)` is the donated, sharded train step.  Call both
+    inside `with mesh, shd.use_mesh(mesh)` so the model's activation
+    constraints trace against the mesh."""
+    def init_fn():
+        return train_lib.init_state(jax.random.PRNGKey(seed), cfg, tcfg)
+
+    state_sh = shd.params_shardings(jax.eval_shape(init_fn), mesh)
+    init = jax.jit(init_fn, out_shardings=state_sh)
+    step = jax.jit(train_lib.make_train_step(cfg, tcfg),
+                   in_shardings=(state_sh, None),
+                   out_shardings=(state_sh, None), donate_argnums=(0,))
+    return init, state_sh, step
 
 
 def main(argv=None) -> dict:
@@ -46,6 +67,9 @@ def main(argv=None) -> dict:
     ap.add_argument("--data-path", default=None,
                     help="memmap token corpus; default synthetic")
     ap.add_argument("--log-every", type=int, default=10)
+    ap.add_argument("--mesh", default="1x1", metavar="DATAxMODEL",
+                    help="(data, model) mesh over the devices present, "
+                         "e.g. 2x2 on four chips")
     ap.add_argument("--kernel-backend", default=None,
                     choices=("pallas-tpu", "pallas-interpret", "xla-einsum",
                              "pallas-tpu-sparse", "xla-sparse"),
@@ -66,27 +90,20 @@ def main(argv=None) -> dict:
         kernel_backend=args.kernel_backend,
         sparsity=args.sparsity,
     )
-    mesh = make_test_mesh()
+    mesh = make_mesh(parse_mesh(args.mesh), ("data", "model"))
     source = make_source(cfg, DataConfig(args.batch, args.seq, args.seed),
                          args.data_path)
+    enable_compile_cache()
 
     with mesh, shd.use_mesh(mesh):
-        def init_fn():
-            return train_lib.init_state(jax.random.PRNGKey(args.seed), cfg,
-                                        tcfg)
-
-        state_sh = shd.params_shardings(jax.eval_shape(init_fn), mesh)
-        step_fn = jax.jit(train_lib.make_train_step(cfg, tcfg),
-                          in_shardings=(state_sh, None),
-                          donate_argnums=(0,))
-
+        init, state_sh, step_fn = build_train(cfg, tcfg, mesh, args.seed)
         ckpt = Checkpointer(args.ckpt_dir) if args.ckpt_dir else None
         if ckpt and args.resume == "auto":
             # Elastic: the checkpoint may come from any mesh shape;
             # placement is re-derived for *this* mesh (DESIGN.md §4).
-            start, state = reshard.resume_or_init(ckpt, init_fn, mesh)
+            start, state = reshard.resume_or_init(ckpt, init, mesh)
         else:
-            start, state = 0, init_fn()
+            start, state = 0, init()
         if start:
             print(f"resumed from step {start}")
         if start >= args.steps:

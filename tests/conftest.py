@@ -1,17 +1,14 @@
-"""Activate the deterministic hypothesis stand-in (tests/_compat) only
-when the real package is absent — some containers ship the jax toolchain
-without hypothesis, and property tests should still run there rather
-than kill collection.  pyproject.toml declares the real dependency."""
+"""Shared test configuration.
 
-import os
-import sys
+Property tests run with no hypothesis deadline: the first example of
+most of them includes a jit compile, which takes far longer than any
+per-example deadline and says nothing about the property."""
 
 import pytest
+from hypothesis import settings
 
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "_compat"))
+settings.register_profile("repro", deadline=None)
+settings.load_profile("repro")
 
 
 @pytest.fixture(scope="module", autouse=True)

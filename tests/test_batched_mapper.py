@@ -1,7 +1,7 @@
 """Batched mapper search engine vs the scalar oracle (the PR-2 gate).
 
-Property tests run under the real hypothesis package or the
-deterministic tests/_compat shim, whichever conftest activated.
+Property tests run under hypothesis with the settings profile that
+tests/conftest.py loads.
 """
 
 import numpy as np

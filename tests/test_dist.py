@@ -13,10 +13,11 @@ import textwrap
 
 import jax
 import pytest
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
 from repro.dist import sharding as shd
-from repro.launch.mesh import make_test_mesh
+from repro.launch.mesh import make_mesh, make_test_mesh, parse_mesh
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -64,10 +65,34 @@ def test_constrain_noop_without_mesh():
     assert shd.constrain(x, "batch", None) is x
 
 
+@pytest.mark.parametrize("spec,want", [
+    ("1x1", (1, 1)), ("2x2", (2, 2)), ("4X2", (4, 2)),
+    ("2", None), ("0x2", None), ("2x2x2", None), ("axb", None)])
+def test_parse_mesh(spec, want):
+    if want is None:
+        with pytest.raises(ValueError):
+            parse_mesh(spec)
+    else:
+        assert parse_mesh(spec) == want
+
+
+def test_make_mesh_auto_axes_and_device_count():
+    """Auto axes accept the model's logical-axis constraints; a mesh
+    larger than the host raises instead of silently shrinking."""
+    mesh = make_mesh((1, 1), ("data", "model"))
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+    with mesh, shd.use_mesh(mesh):
+        y = jax.jit(lambda x: shd.constrain(x * 2, "batch", None))(
+            jax.numpy.ones((4, 4)))
+    assert float(y.sum()) == 32.0
+    with pytest.raises(ValueError, match="devices"):
+        make_mesh((len(jax.devices()) + 1, 1), ("data", "model"))
+
+
 @pytest.mark.slow
 def test_multidevice_train_and_dryrun():
     code = textwrap.dedent("""
-        import json
+        import json, os, tempfile
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh
         from repro.configs import get_config
@@ -76,18 +101,17 @@ def test_multidevice_train_and_dryrun():
         from repro.train_lib import train as train_lib
 
         from repro.optim.adamw import AdamWConfig
+        from repro.launch.mesh import make_mesh
+        from repro.launch.train import build_train, main
         assert len(jax.devices()) == 8
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_config("qwen2-1.5b", smoke=True)
         tcfg = train_lib.TrainConfig(microbatches=2,
                                      compute_dtype=jnp.float32,
                                      optimizer=AdamWConfig(lr=5e-3))
         with mesh, shd.use_mesh(mesh):
-            state = train_lib.init_state(jax.random.PRNGKey(0), cfg, tcfg)
-            sh = shd.params_shardings(state, mesh)
-            state = jax.tree.map(jax.device_put, state, sh)
-            step = jax.jit(train_lib.make_train_step(cfg, tcfg),
-                           in_shardings=(sh, None), donate_argnums=(0,))
+            init, _, step = build_train(cfg, tcfg, mesh, 0)
+            state = init()
             src = make_source(cfg, DataConfig(batch=8, seq_len=32))
             losses = []
             for s in range(6):
@@ -106,11 +130,18 @@ def test_multidevice_train_and_dryrun():
                                                     src2.batch(s)))
             ref.append(float(m2["ce"]))
         err = max(abs(a - b) for a, b in zip(losses, ref, strict=True))
-        print(json.dumps({"losses": losses, "ref": ref, "err": err}))
+        # the launcher on a named mesh: same first step as above
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = tempfile.mkdtemp()
+        cli = main(["--arch", "qwen2-1.5b", "--smoke", "--steps", "2",
+                    "--batch", "8", "--seq", "32", "--microbatches", "2",
+                    "--mesh", "4x2"])
+        print(json.dumps({"losses": losses, "ref": ref, "err": err,
+                          "cli_first_ce": cli["first_ce"]}))
     """)
     out = _run_subprocess(code)
     assert out["losses"][-1] < out["losses"][0] - 0.1
     assert out["err"] < 5e-3, out  # SPMD == single-device math
+    assert abs(out["cli_first_ce"] - out["ref"][0]) < 5e-3, out
 
 
 @pytest.mark.slow
@@ -122,19 +153,20 @@ def test_elastic_reshard_restore():
         from repro.configs import get_config
         from repro.dist import sharding as shd
         from repro.checkpoint.checkpoint import Checkpointer
+        from repro.launch.mesh import make_mesh
         from repro.train_lib import train as train_lib
 
         cfg = get_config("qwen2-1.5b", smoke=True)
         tcfg = train_lib.TrainConfig(compute_dtype=jnp.float32)
         d = tempfile.mkdtemp()
-        mesh1 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh1 = make_mesh((4, 2), ("data", "model"))
         with mesh1, shd.use_mesh(mesh1):
             state = train_lib.init_state(jax.random.PRNGKey(0), cfg, tcfg)
             sh1 = shd.params_shardings(state, mesh1)
             state = jax.tree.map(jax.device_put, state, sh1)
             ck = Checkpointer(d)
             ck.save(1, state, blocking=True)
-        mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+        mesh2 = make_mesh((2, 4), ("data", "model"))
         with mesh2, shd.use_mesh(mesh2):
             like = jax.eval_shape(lambda: train_lib.init_state(
                 jax.random.PRNGKey(0), cfg, tcfg))
@@ -161,8 +193,9 @@ def test_tiny_dryrun_cell_multipod():
         from repro.configs.shapes import ShapeSpec
         from repro.train_lib.train import TrainConfig, make_train_step
         from repro.roofline import hlo_costs
+        from repro.launch.mesh import make_mesh
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = get_config("qwen2-1.5b", smoke=True)
         shape = ShapeSpec("tiny_train", 64, 8, "train")
         tcfg = TrainConfig(microbatches=2, compute_dtype=jnp.bfloat16)

@@ -7,6 +7,7 @@ import tempfile
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.checkpoint.checkpoint import Checkpointer, resume_or_init
 from repro.configs import get_config
@@ -110,7 +111,40 @@ def test_data_pipeline_deterministic():
         np.testing.assert_array_equal(m.batch(0)["tokens"], mb["tokens"])
 
 
-def test_train_launcher_end_to_end():
+@pytest.fixture
+def compile_cache_dir(tmp_path, monkeypatch):
+    """Send the launchers' persistent compile cache to `tmp_path`, and
+    restore jax's cache state afterwards so later tests compile as
+    before."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    yield tmp_path
+    jax.config.update("jax_compilation_cache_dir", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir(compile_cache_dir, monkeypatch, from_env):
+    """The variable, when set, is the only cache directory; otherwise
+    the cache sits at the one fixed, gitignored path of the checkout."""
+    from repro.launch.compile_cache import REPO_CACHE_DIR, enable_compile_cache
+
+    if from_env:
+        want = str(compile_cache_dir)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = str(REPO_CACHE_DIR)
+        root = REPO_CACHE_DIR.parent
+        assert (root / "src" / "repro").is_dir()
+        ignored = (root / ".gitignore").read_text().split()
+        assert f"{REPO_CACHE_DIR.name}/" in ignored
+    assert enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+
+
+def test_train_launcher_end_to_end(compile_cache_dir):
     from repro.launch.train import main
     with tempfile.TemporaryDirectory() as d:
         out = main(["--arch", "qwen2-1.5b", "--smoke", "--steps", "12",
@@ -124,7 +158,7 @@ def test_train_launcher_end_to_end():
         assert out2["steps"] == 14
 
 
-def test_serve_launcher_end_to_end():
+def test_serve_launcher_end_to_end(compile_cache_dir):
     from repro.launch.serve import main
     out = main(["--arch", "qwen2-1.5b", "--smoke", "--batch", "2",
                 "--prompt-len", "8", "--gen", "4"])
