@@ -1,0 +1,6 @@
+"""Median time from due to the start of the tick that admitted the request (left the scheduler's queue)."""
+import readings
+
+
+def read(run):
+    return readings.pct(readings.queue_wait_ms(run), 50)
