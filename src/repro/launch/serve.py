@@ -87,7 +87,9 @@ def _run_trace(params, cfg, scfg, args, trace) -> dict:
     for uid in sorted(comps)[:8]:
         c = comps[uid]
         print(f"  req {uid}: prompt {c.prompt_len} -> {len(c.tokens)} tokens "
-              f"({c.finish_reason}, steps {c.admit_step}..{c.finish_step})")
+              f"({c.finish_reason}, steps {c.admit_step}..{c.finish_step}, "
+              f"queue wait {(c.admitted_s - c.submitted_s) * 1e3:.1f} ms, "
+              f"ttft {(c.first_token_s - c.submitted_s) * 1e3:.1f} ms)")
     out = {"tokens_per_s": n_tok / dt, "requests": len(comps),
            "decode_steps": sched.stats["decode_steps"]}
     if sched.engine is not None:

@@ -57,10 +57,12 @@ import dataclasses
 import functools
 import queue
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import engine as engine_mod
 from repro.models import transformer as T
@@ -90,6 +92,13 @@ class Completion:
     prompt_len: int
     admit_step: int
     finish_step: int
+    # time.perf_counter() readings: `submit`, the pick in `_admit`, the
+    # first and the last emitted token (under chunked ingestion the
+    # first token comes with the final chunk)
+    submitted_s: float
+    admitted_s: float
+    first_token_s: float
+    finished_s: float
 
 
 @dataclasses.dataclass
@@ -99,6 +108,9 @@ class _Slot:
     emitted: list[int]
     last_token: int
     admit_step: int
+    submitted_s: float
+    admitted_s: float
+    first_token_s: float = 0.0
     # chunked ingestion (DESIGN.md §12): tokens of the prompt already
     # resident in the cache (shared-prefix pages included); while
     # `ingesting` the slot sits out of decode ticks and receives one
@@ -122,34 +134,43 @@ def _jitted_steps(cfg: ArchConfig, scfg: serve_lib.ServeConfig, engine,
     §12): the contiguous layout needs a separate trace that threads
     `hist_len`; the paged prefill already does (chunk history rides the
     same gathered-pages path shared prefixes use), so there the chunk
-    step IS the admit step."""
+    step IS the admit step.
+
+    Each jit wraps a named function, so its programs carry a stable
+    name (`jit_serve_prefill`, ...) in compiler dumps and device
+    traces."""
     if paged:
-        def _paged_prefill(p, tok, cache, lens, mask, bt, hist, *,
-                           hist_pages):
+        def serve_prefill(p, tok, cache, lens, mask, bt, hist, *,
+                          hist_pages):
             return T.prefill(p, cfg, tok, cache,
                              compute_dtype=scfg.compute_dtype, lengths=lens,
                              update_mask=mask, block_tables=bt,
                              hist_len=hist, hist_pages=hist_pages)
 
-        prefill = jax.jit(_paged_prefill, static_argnames=("hist_pages",))
-        decode = jax.jit(
-            lambda p, cache, tok, act, bt: T.decode_step(
-                p, cfg, cache, tok, compute_dtype=scfg.compute_dtype,
-                active=act, block_tables=bt))
-        return prefill, decode, prefill
-    prefill = jax.jit(
-        lambda p, tok, cache, lens, mask: T.prefill(
-            p, cfg, tok, cache, compute_dtype=scfg.compute_dtype,
-            lengths=lens, update_mask=mask))
-    decode = jax.jit(
-        lambda p, cache, tok, act: T.decode_step(
-            p, cfg, cache, tok, compute_dtype=scfg.compute_dtype,
-            active=act))
-    chunk_prefill = jax.jit(
-        lambda p, tok, cache, lens, mask, hist: T.prefill(
-            p, cfg, tok, cache, compute_dtype=scfg.compute_dtype,
-            lengths=lens, update_mask=mask, hist_len=hist))
-    return prefill, decode, chunk_prefill
+        def serve_decode(p, cache, tok, act, bt):
+            return T.decode_step(p, cfg, cache, tok,
+                                 compute_dtype=scfg.compute_dtype,
+                                 active=act, block_tables=bt)
+
+        prefill = jax.jit(serve_prefill, static_argnames=("hist_pages",))
+        return prefill, jax.jit(serve_decode), prefill
+
+    def serve_prefill(p, tok, cache, lens, mask):
+        return T.prefill(p, cfg, tok, cache,
+                         compute_dtype=scfg.compute_dtype, lengths=lens,
+                         update_mask=mask)
+
+    def serve_decode(p, cache, tok, act):
+        return T.decode_step(p, cfg, cache, tok,
+                             compute_dtype=scfg.compute_dtype, active=act)
+
+    def serve_chunk_prefill(p, tok, cache, lens, mask, hist):
+        return T.prefill(p, cfg, tok, cache,
+                         compute_dtype=scfg.compute_dtype, lengths=lens,
+                         update_mask=mask, hist_len=hist)
+
+    return (jax.jit(serve_prefill), jax.jit(serve_decode),
+            jax.jit(serve_chunk_prefill))
 
 
 @functools.lru_cache(maxsize=64)
@@ -161,31 +182,35 @@ def _jitted_spec_steps(cfg: ArchConfig, dcfg: ArchConfig,
     the target, `advance` replaying the verify window through the
     persistent draft cache, and the draft's own ragged prefill.  The
     draft cache is always contiguous (it is private per scheduler and
-    never shares prefixes), so only `verify` has a paged variant."""
+    never shares prefixes), so only `verify` has a paged variant.
+    Named functions, as in `_jitted_steps`."""
     k = scfg.speculate_k
     if paged:
-        verify = jax.jit(
-            lambda p, cache, toks, act, bt: T.verify_step(
-                p, cfg, cache, toks, compute_dtype=scfg.compute_dtype,
-                active=act, block_tables=bt))
+        def serve_verify(p, cache, toks, act, bt):
+            return T.verify_step(p, cfg, cache, toks,
+                                 compute_dtype=scfg.compute_dtype,
+                                 active=act, block_tables=bt)
     else:
-        verify = jax.jit(
-            lambda p, cache, toks, act: T.verify_step(
-                p, cfg, cache, toks, compute_dtype=scfg.compute_dtype,
-                active=act))
-    propose = jax.jit(
-        lambda p, cache, tok, act: T.draft_propose(
-            p, dcfg, cache, tok, k, compute_dtype=scfg.compute_dtype,
-            active=act))
-    advance = jax.jit(
-        lambda p, cache, toks, keep, act: T.spec_advance(
-            p, dcfg, cache, toks, keep, compute_dtype=scfg.compute_dtype,
-            active=act))
-    dprefill = jax.jit(
-        lambda p, tok, cache, lens, mask: T.prefill(
-            p, dcfg, tok, cache, compute_dtype=scfg.compute_dtype,
-            lengths=lens, update_mask=mask))
-    return verify, propose, advance, dprefill
+        def serve_verify(p, cache, toks, act):
+            return T.verify_step(p, cfg, cache, toks,
+                                 compute_dtype=scfg.compute_dtype,
+                                 active=act)
+
+    def serve_propose(p, cache, tok, act):
+        return T.draft_propose(p, dcfg, cache, tok, k,
+                               compute_dtype=scfg.compute_dtype, active=act)
+
+    def serve_advance(p, cache, toks, keep, act):
+        return T.spec_advance(p, dcfg, cache, toks, keep,
+                              compute_dtype=scfg.compute_dtype, active=act)
+
+    def serve_draft_prefill(p, tok, cache, lens, mask):
+        return T.prefill(p, dcfg, tok, cache,
+                         compute_dtype=scfg.compute_dtype, lengths=lens,
+                         update_mask=mask)
+
+    return (jax.jit(serve_verify), jax.jit(serve_propose),
+            jax.jit(serve_advance), jax.jit(serve_draft_prefill))
 
 
 class Scheduler:
@@ -244,13 +269,21 @@ class Scheduler:
                       # prefill_width_sum is PER-SLOT: each prefill call
                       # adds its width once per admitted slot, so
                       # bucketing mixed-history admits by hist_pages
-                      # shows up as a drop (PR 7)
+                      # shows up as a drop (PR 7).  It is not what the
+                      # device computes: that is prefill_rows, batch x
+                      # width per prefill or chunk call (every slot of
+                      # the pool, padding included)
                       "prefill_tokens": 0, "prefill_width_sum": 0,
-                      "shared_prefix_tokens": 0,
+                      "prefill_rows": 0, "shared_prefix_tokens": 0,
+                      # decode/verify calls: active slots, and their
+                      # resident context rows (each slot's clock as
+                      # the call starts), summed over calls
+                      "decode_slots": 0, "decode_kv_rows": 0,
                       # speculative plane (DESIGN.md §9)
                       "spec_ticks": 0, "draft_tokens": 0,
                       "accepted_draft_tokens": 0}
         self._live_uids: set[int] = set()
+        self._submitted_s: dict[int, float] = {}  # queued uid -> submit
         self._prefill, self._decode, self._chunk_prefill = _jitted_steps(
             cfg, scfg, self.engine, self.paged is not None)
         # chunked ingestion (DESIGN.md §12): chunk calls are always
@@ -324,6 +357,7 @@ class Scheduler:
         if req.uid in self._live_uids:  # queued, in flight, or completed
             raise ValueError(f"duplicate request uid {req.uid}")
         self._live_uids.add(req.uid)
+        self._submitted_s[req.uid] = time.perf_counter()
         self.queue.append(req)
 
     @property
@@ -346,6 +380,9 @@ class Scheduler:
     def _emit(self, i: int, tok: int, finished: list[Completion]) -> None:
         """Record one sampled token for slot i; evict on EOS/budget."""
         slot = self.slots[i]
+        now = time.perf_counter()
+        if not slot.emitted:
+            slot.first_token_s = now
         slot.emitted.append(tok)
         slot.last_token = tok
         done_eos = slot.req.eos_id is not None and tok == slot.req.eos_id
@@ -356,7 +393,9 @@ class Scheduler:
                 tokens=np.asarray(slot.emitted, np.int32),
                 finish_reason="eos" if done_eos else "length",
                 prompt_len=int(np.asarray(slot.req.prompt).size),
-                admit_step=slot.admit_step, finish_step=self.step_count)
+                admit_step=slot.admit_step, finish_step=self.step_count,
+                submitted_s=slot.submitted_s, admitted_s=slot.admitted_s,
+                first_token_s=slot.first_token_s, finished_s=now)
             self.completions[slot.req.uid] = comp
             finished.append(comp)
             self.slots[i] = None  # slot free for the next queued request
@@ -366,12 +405,24 @@ class Scheduler:
                 self.paged.release(i)
             self.stats["finished"] += 1
 
-    # -- the two batch calls ----------------------------------------------
+    # -- the batch calls ------------------------------------------------------
+    #
+    # Each call's host work runs under `jax.profiler.TraceAnnotation`
+    # spans (README "Observing the scheduler"): `serve.stage` builds the
+    # inputs and dispatches the program, `serve.pull` brings its output
+    # to the host (where the host waits for the device), `serve.sample`
+    # picks and records the tokens.  The three never nest, so device
+    # idle inside one is counted once.
 
     def _admit(self, finished: list[Completion]) -> None:
         free = [i for i, s in enumerate(self.slots) if s is None]
         if not free or not self.queue:
             return
+        with TraceAnnotation("serve.admit"):
+            self._admit_picks(free, finished)
+
+    def _admit_picks(self, free: list[int],
+                     finished: list[Completion]) -> None:
         picks: list[tuple[int, Request]] = []
         hists: dict[int, int] = {}
         if self.paged is not None:
@@ -401,6 +452,12 @@ class Scheduler:
         else:
             while free and self.queue:
                 picks.append((free.pop(0), self.queue.popleft()))
+        now = time.perf_counter()
+        for i, req in picks:
+            self.slots[i] = _Slot(
+                req=req, key=req.key, emitted=[], last_token=0,
+                admit_step=self.step_count,
+                submitted_s=self._submitted_s.pop(req.uid), admitted_s=now)
         self.stats["admitted"] += len(picks)
         # Chunked ingestion (DESIGN.md §12): a pick whose un-resident
         # suffix exceeds the chunk does NOT prefill here — its slot
@@ -412,10 +469,8 @@ class Scheduler:
             for i, req in picks:
                 n = int(np.asarray(req.prompt).size)
                 if n - hists.get(i, 0) > self.chunk:
-                    self.slots[i] = _Slot(
-                        req=req, key=req.key, emitted=[], last_token=0,
-                        admit_step=self.step_count,
-                        ingest_pos=hists.get(i, 0), ingesting=True)
+                    self.slots[i].ingest_pos = hists.get(i, 0)
+                    self.slots[i].ingesting = True
                 else:
                     short.append((i, req))
             picks = short
@@ -430,67 +485,69 @@ class Scheduler:
             hp = hists.get(i, 0) // self.scfg.page_size \
                 if self.paged is not None else 0
             buckets.setdefault(hp, []).append((i, req))
-        rows: dict[int, np.ndarray] = {}
         for hp in sorted(buckets):
-            rows.update(self._prefill_group(buckets[hp], hists, hp))
+            self._prefill_group(buckets[hp], hists, hp, finished)
         if self.paged is not None:
-            # index the now-resident full prompt pages so later
-            # admissions with the same prefix reuse them (ingesting
-            # slots defer to their final chunk — the index must not
-            # advertise pages whose rows are not written yet)
-            for i, req in picks:
-                self.paged.note_prefilled(
-                    i, np.asarray(req.prompt, np.int32).tolist())
             self.stats["shared_prefix_tokens"] = self.paged.shared_tokens
         if self.spec_k and picks:
             self._draft_prefill(picks)
-        # first output token comes from the prefill logits (same
-        # semantics as serve.generate)
-        for i, _ in picks:
-            self._emit(i, self._sample(self.slots[i], rows[i]), finished)
 
     def _prefill_group(self, picks: list[tuple[int, Request]],
-                       hists: dict[int, int],
-                       hist_pages: int) -> dict[int, np.ndarray]:
+                       hists: dict[int, int], hist_pages: int,
+                       finished: list[Completion]) -> None:
         """One ragged prefill call over `picks` (all sharing
-        `hist_pages` resident history pages); returns each admitted
-        slot's last-token logits row."""
+        `hist_pages` resident history pages); each admitted slot's first
+        output token comes from its last-token logits row (same
+        semantics as serve.generate)."""
         b = self.scfg.batch
         # with a prefix-cache hit only the un-resident suffix prefills
         maxlen = max(int(np.asarray(r.prompt).size) - hists.get(i, 0)
                      for i, r in picks)
         width = -(-maxlen // self.prefill_bucket) * self.prefill_bucket
         width = min(width, self.scfg.max_seq)
-        tokens = np.zeros((b, width), np.int32)
-        lengths = np.ones((b,), np.int32)
-        mask = np.zeros((b,), bool)
-        hist_arr = np.zeros((b,), np.int32)
-        for i, req in picks:
-            prompt = np.asarray(req.prompt, np.int32).reshape(-1)
-            suffix = prompt[hists.get(i, 0):]
-            tokens[i, : suffix.size] = suffix
-            lengths[i] = suffix.size
-            hist_arr[i] = hists.get(i, 0)
-            mask[i] = True
-            self.slots[i] = _Slot(req=req, key=req.key, emitted=[],
-                                  last_token=0, admit_step=self.step_count)
-        with self._scope():
+        with TraceAnnotation("serve.prefill", width=width, picks=len(picks)):
+            with TraceAnnotation("serve.stage"):
+                tokens = np.zeros((b, width), np.int32)
+                lengths = np.ones((b,), np.int32)
+                mask = np.zeros((b,), bool)
+                hist_arr = np.zeros((b,), np.int32)
+                for i, req in picks:
+                    prompt = np.asarray(req.prompt, np.int32).reshape(-1)
+                    suffix = prompt[hists.get(i, 0):]
+                    tokens[i, : suffix.size] = suffix
+                    lengths[i] = suffix.size
+                    hist_arr[i] = hists.get(i, 0)
+                    mask[i] = True
+                with self._scope():
+                    if self.paged is not None:
+                        logits, self.cache = self._prefill(
+                            self.params, jnp.asarray(tokens), self.cache,
+                            jnp.asarray(lengths), jnp.asarray(mask),
+                            jnp.asarray(self.paged.tables),
+                            jnp.asarray(hist_arr), hist_pages=hist_pages)
+                    else:
+                        logits, self.cache = self._prefill(
+                            self.params, jnp.asarray(tokens), self.cache,
+                            jnp.asarray(lengths), jnp.asarray(mask))
+            with TraceAnnotation("serve.pull"):
+                rows = np.asarray(logits[:, -1], np.float32)
+            self.stats["prefill_calls"] += 1
+            self.stats["prefill_widths"].add(width)
+            self.stats["prefill_tokens"] += int(lengths[mask].sum())
+            self.stats["prefill_width_sum"] += width * len(picks)
+            self.stats["prefill_rows"] += b * width
             if self.paged is not None:
-                logits, self.cache = self._prefill(
-                    self.params, jnp.asarray(tokens), self.cache,
-                    jnp.asarray(lengths), jnp.asarray(mask),
-                    jnp.asarray(self.paged.tables), jnp.asarray(hist_arr),
-                    hist_pages=hist_pages)
-            else:
-                logits, self.cache = self._prefill(
-                    self.params, jnp.asarray(tokens), self.cache,
-                    jnp.asarray(lengths), jnp.asarray(mask))
-        out_rows = np.asarray(logits[:, -1], np.float32)
-        self.stats["prefill_calls"] += 1
-        self.stats["prefill_widths"].add(width)
-        self.stats["prefill_tokens"] += int(lengths[mask].sum())
-        self.stats["prefill_width_sum"] += width * len(picks)
-        return {i: out_rows[i] for i, _ in picks}
+                # index the now-resident full prompt pages so later
+                # admissions with the same prefix reuse them (ingesting
+                # slots defer to their final chunk — the index must not
+                # advertise pages whose rows are not written yet)
+                for i, req in picks:
+                    self.paged.note_prefilled(
+                        i, np.asarray(req.prompt, np.int32).tolist())
+            with TraceAnnotation("serve.sample"):
+                for i, _ in picks:
+                    self._emit(i, self._sample(self.slots[i], rows[i]),
+                               finished)
 
     def _ingest_tick(self, finished: list[Completion]) -> None:
         """Advance every ingesting slot by one `prefill_chunk`-wide
@@ -501,8 +558,8 @@ class Scheduler:
         (`hist_pages` is a static arg) and the shallowest group goes
         first: deeper slots wait a tick, bounding retraces exactly like
         the shared-prefix admit buckets.  A slot whose prompt completes
-        this tick leaves `ingesting`, emits its first output token from
-        the chunk logits, registers its prefix pages, and (when
+        this tick leaves `ingesting`, registers its prefix pages, emits
+        its first output token from the chunk logits, and (when
         speculating) replays its full prompt through the draft cache —
         all the steps the single-shot admit runs, just deferred to the
         final chunk."""
@@ -520,53 +577,60 @@ class Scheduler:
         else:
             hp = 0
         b, ch = self.scfg.batch, self.chunk
-        tokens = np.zeros((b, ch), np.int32)
-        lengths = np.ones((b,), np.int32)
-        mask = np.zeros((b,), bool)
-        hist_arr = np.zeros((b,), np.int32)
-        takes: dict[int, int] = {}
-        for i, s in ing:
-            prompt = np.asarray(s.req.prompt, np.int32).reshape(-1)
-            take = min(ch, prompt.size - s.ingest_pos)
-            tokens[i, :take] = prompt[s.ingest_pos:s.ingest_pos + take]
-            lengths[i] = take
-            hist_arr[i] = s.ingest_pos
-            mask[i] = True
-            takes[i] = take
-        with self._scope():
+        with TraceAnnotation("serve.ingest", width=ch, slots=len(ing)):
+            with TraceAnnotation("serve.stage"):
+                tokens = np.zeros((b, ch), np.int32)
+                lengths = np.ones((b,), np.int32)
+                mask = np.zeros((b,), bool)
+                hist_arr = np.zeros((b,), np.int32)
+                takes: dict[int, int] = {}
+                for i, s in ing:
+                    prompt = np.asarray(s.req.prompt, np.int32).reshape(-1)
+                    take = min(ch, prompt.size - s.ingest_pos)
+                    tokens[i, :take] = prompt[s.ingest_pos:
+                                              s.ingest_pos + take]
+                    lengths[i] = take
+                    hist_arr[i] = s.ingest_pos
+                    mask[i] = True
+                    takes[i] = take
+                with self._scope():
+                    if self.paged is not None:
+                        logits, self.cache = self._chunk_prefill(
+                            self.params, jnp.asarray(tokens), self.cache,
+                            jnp.asarray(lengths), jnp.asarray(mask),
+                            jnp.asarray(self.paged.tables),
+                            jnp.asarray(hist_arr), hist_pages=hp)
+                    else:
+                        logits, self.cache = self._chunk_prefill(
+                            self.params, jnp.asarray(tokens), self.cache,
+                            jnp.asarray(lengths), jnp.asarray(mask),
+                            jnp.asarray(hist_arr))
+            with TraceAnnotation("serve.pull"):
+                rows = np.asarray(logits[:, -1], np.float32)
+            self.stats["prefill_calls"] += 1
+            self.stats["prefill_widths"].add(ch)
+            self.stats["prefill_tokens"] += sum(takes.values())
+            self.stats["prefill_width_sum"] += ch * len(ing)
+            self.stats["prefill_rows"] += b * ch
+            done: list[tuple[int, Request]] = []
+            for i, s in ing:
+                s.ingest_pos += takes[i]
+                if s.ingest_pos >= int(np.asarray(s.req.prompt).size):
+                    s.ingesting = False
+                    done.append((i, s.req))
+            if not done:
+                return
             if self.paged is not None:
-                logits, self.cache = self._chunk_prefill(
-                    self.params, jnp.asarray(tokens), self.cache,
-                    jnp.asarray(lengths), jnp.asarray(mask),
-                    jnp.asarray(self.paged.tables), jnp.asarray(hist_arr),
-                    hist_pages=hp)
-            else:
-                logits, self.cache = self._chunk_prefill(
-                    self.params, jnp.asarray(tokens), self.cache,
-                    jnp.asarray(lengths), jnp.asarray(mask),
-                    jnp.asarray(hist_arr))
-        rows = np.asarray(logits[:, -1], np.float32)
-        self.stats["prefill_calls"] += 1
-        self.stats["prefill_widths"].add(ch)
-        self.stats["prefill_tokens"] += sum(takes.values())
-        self.stats["prefill_width_sum"] += ch * len(ing)
-        done: list[tuple[int, Request]] = []
-        for i, s in ing:
-            s.ingest_pos += takes[i]
-            if s.ingest_pos >= int(np.asarray(s.req.prompt).size):
-                s.ingesting = False
-                done.append((i, s.req))
-        if not done:
-            return
-        if self.paged is not None:
-            for i, req in done:
-                self.paged.note_prefilled(
-                    i, np.asarray(req.prompt, np.int32).tolist())
-            self.stats["shared_prefix_tokens"] = self.paged.shared_tokens
-        if self.spec_k:
-            self._draft_prefill(done)
-        for i, _ in done:
-            self._emit(i, self._sample(self.slots[i], rows[i]), finished)
+                for i, req in done:
+                    self.paged.note_prefilled(
+                        i, np.asarray(req.prompt, np.int32).tolist())
+                self.stats["shared_prefix_tokens"] = self.paged.shared_tokens
+            with TraceAnnotation("serve.sample"):
+                for i, _ in done:
+                    self._emit(i, self._sample(self.slots[i], rows[i]),
+                               finished)
+            if self.spec_k:
+                self._draft_prefill(done)
 
     def _draft_prefill(self, picks: list[tuple[int, Request]]) -> None:
         """Prefill the draft cache with the FULL prompts of the slots
@@ -578,18 +642,28 @@ class Scheduler:
         maxlen = max(int(np.asarray(r.prompt).size) for _, r in picks)
         width = -(-maxlen // self.prefill_bucket) * self.prefill_bucket
         width = min(width, self.scfg.max_seq)
-        tokens = np.zeros((b, width), np.int32)
-        lengths = np.ones((b,), np.int32)
-        mask = np.zeros((b,), bool)
-        for i, req in picks:
-            prompt = np.asarray(req.prompt, np.int32).reshape(-1)
-            tokens[i, : prompt.size] = prompt
-            lengths[i] = prompt.size
-            mask[i] = True
-        with self._scope():
-            _, self.draft_cache = self._dprefill(
-                self.draft_params, jnp.asarray(tokens), self.draft_cache,
-                jnp.asarray(lengths), jnp.asarray(mask))
+        with TraceAnnotation("serve.draft_prefill", width=width,
+                             picks=len(picks)), \
+                TraceAnnotation("serve.stage"):
+            tokens = np.zeros((b, width), np.int32)
+            lengths = np.ones((b,), np.int32)
+            mask = np.zeros((b,), bool)
+            for i, req in picks:
+                prompt = np.asarray(req.prompt, np.int32).reshape(-1)
+                tokens[i, : prompt.size] = prompt
+                lengths[i] = prompt.size
+                mask[i] = True
+            with self._scope():
+                _, self.draft_cache = self._dprefill(
+                    self.draft_params, jnp.asarray(tokens), self.draft_cache,
+                    jnp.asarray(lengths), jnp.asarray(mask))
+
+    def _clocks(self, active: np.ndarray) -> dict[int, int]:
+        """Each active slot's clock: its resident rows, and the position
+        its next token is written at (prompt_len + emitted - 1; the
+        first emitted token came from prefill, not decode)."""
+        return {i: int(np.asarray(s.req.prompt).size) + len(s.emitted) - 1
+                for i, s in enumerate(self.slots) if active[i]}
 
     def _decode_active(self, finished: list[Completion]) -> None:
         # ingesting slots sit decode out: their prompt is still streaming
@@ -598,34 +672,40 @@ class Scheduler:
             [s is not None and not s.ingesting for s in self.slots])
         if not active.any():
             return
-        toks = np.asarray(
-            [s.last_token if s is not None else 0 for s in self.slots],
-            np.int32)[:, None]
-        if self.paged is not None:
-            # make each active slot's write-frontier page exist (and be
-            # private — asserted) before the fused step writes it.  The
-            # write position is the slot's clock: prompt_len + emitted - 1
-            # (the first emitted token came from prefill, not decode).
-            for i, s in enumerate(self.slots):
-                if active[i]:
-                    pos = (int(np.asarray(s.req.prompt).size)
-                           + len(s.emitted) - 1)
-                    self.paged.ensure_decode_page(i, pos)
-        with self._scope():
-            if self.paged is not None:
-                logits, self.cache = self._decode(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(active), jnp.asarray(self.paged.tables))
-            else:
-                logits, self.cache = self._decode(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(active))
-        rows = np.asarray(logits[:, -1], np.float32)
-        self.stats["decode_steps"] += 1
-        self.stats["decode_tokens"] += int(active.sum())
-        for i in range(len(self.slots)):
-            if active[i]:
-                self._emit(i, self._sample(self.slots[i], rows[i]), finished)
+        n_active = int(active.sum())
+        with TraceAnnotation("serve.decode", slots=n_active):
+            with TraceAnnotation("serve.stage"):
+                toks = np.asarray(
+                    [s.last_token if s is not None else 0
+                     for s in self.slots], np.int32)[:, None]
+                clocks = self._clocks(active)
+                if self.paged is not None:
+                    # make each active slot's write-frontier page exist
+                    # (and be private — asserted) before the fused step
+                    # writes it
+                    for i, pos in clocks.items():
+                        self.paged.ensure_decode_page(i, pos)
+                with self._scope():
+                    if self.paged is not None:
+                        logits, self.cache = self._decode(
+                            self.params, self.cache, jnp.asarray(toks),
+                            jnp.asarray(active),
+                            jnp.asarray(self.paged.tables))
+                    else:
+                        logits, self.cache = self._decode(
+                            self.params, self.cache, jnp.asarray(toks),
+                            jnp.asarray(active))
+            with TraceAnnotation("serve.pull"):
+                rows = np.asarray(logits[:, -1], np.float32)
+            self.stats["decode_steps"] += 1
+            self.stats["decode_tokens"] += n_active
+            self.stats["decode_slots"] += n_active
+            self.stats["decode_kv_rows"] += sum(clocks.values())
+            with TraceAnnotation("serve.sample"):
+                for i in range(len(self.slots)):
+                    if active[i]:
+                        self._emit(i, self._sample(self.slots[i], rows[i]),
+                                   finished)
 
     def _spec_tick(self, finished: list[Completion]) -> None:
         """One speculative tick (DESIGN.md §9): draft k tokens, verify
@@ -638,58 +718,63 @@ class Scheduler:
         if not active.any():
             return
         k = self.spec_k
-        last = np.asarray(
-            [s.last_token if s is not None else 0 for s in self.slots],
-            np.int32)
-        if self.paged is not None:
-            # the verify writes span pos..pos+k: make every page on the
-            # span exist (and be private) before the fused pass
-            for i, s in enumerate(self.slots):
-                if active[i]:
-                    pos = (int(np.asarray(s.req.prompt).size)
-                           + len(s.emitted) - 1)
+        n_active = int(active.sum())
+        with TraceAnnotation("serve.spec", slots=n_active):
+            with TraceAnnotation("serve.stage"):
+                last = np.asarray(
+                    [s.last_token if s is not None else 0
+                     for s in self.slots], np.int32)
+                clocks = self._clocks(active)
+                if self.paged is not None:
+                    # the verify writes span pos..pos+k: make every page
+                    # on the span exist (and be private) before the
+                    # fused pass
                     page = self.paged.page
-                    for pg in range(pos // page, (pos + k) // page + 1):
-                        self.paged.ensure_decode_page(
-                            i, max(pos, pg * page))
-        act = jnp.asarray(active)
-        with self._scope():
-            drafts = self._propose(self.draft_params, self.draft_cache,
-                                   jnp.asarray(last), act)
-            toks = jnp.concatenate([jnp.asarray(last)[:, None], drafts],
-                                   axis=1)
-            if self.paged is not None:
-                g, n_acc, self.cache = self._verify(
-                    self.params, self.cache, toks, act,
-                    jnp.asarray(self.paged.tables))
-            else:
-                g, n_acc, self.cache = self._verify(
-                    self.params, self.cache, toks, act)
-            self.draft_cache = self._advance(
-                self.draft_params, self.draft_cache, toks, n_acc + 1, act)
-        g_np = np.asarray(g)
-        acc_np = np.asarray(n_acc)
-        self.stats["decode_steps"] += 1
-        self.stats["spec_ticks"] += 1
-        self.stats["draft_tokens"] += k * int(active.sum())
-        self.stats["accepted_draft_tokens"] += int(acc_np[active].sum())
-        for i in range(len(self.slots)):
-            if not active[i]:
-                continue
-            s = self.slots[i]
-            # committed write frontier BEFORE this tick's emissions
-            t0 = int(np.asarray(s.req.prompt).size) + len(s.emitted) - 1
-            for j in range(int(acc_np[i]) + 1):
-                if self.slots[i] is None:  # EOS/budget mid-window
-                    break
-                self._emit(i, int(g_np[i, j]), finished)
-                self.stats["decode_tokens"] += 1
-            if self.paged is not None and self.slots[i] is not None:
-                # clock-decrement rollback happened in-graph; release
-                # any page now holding only rejected rows.  The last
-                # committed row is t0 + n_acc (keep = n_acc + 1 rows
-                # starting at t0).
-                self.paged.rollback(i, t0 + int(acc_np[i]))
+                    for i, pos in clocks.items():
+                        for pg in range(pos // page, (pos + k) // page + 1):
+                            self.paged.ensure_decode_page(
+                                i, max(pos, pg * page))
+                act = jnp.asarray(active)
+                with self._scope():
+                    drafts = self._propose(self.draft_params,
+                                           self.draft_cache,
+                                           jnp.asarray(last), act)
+                    toks = jnp.concatenate(
+                        [jnp.asarray(last)[:, None], drafts], axis=1)
+                    if self.paged is not None:
+                        g, n_acc, self.cache = self._verify(
+                            self.params, self.cache, toks, act,
+                            jnp.asarray(self.paged.tables))
+                    else:
+                        g, n_acc, self.cache = self._verify(
+                            self.params, self.cache, toks, act)
+                    self.draft_cache = self._advance(
+                        self.draft_params, self.draft_cache, toks,
+                        n_acc + 1, act)
+            with TraceAnnotation("serve.pull"):
+                g_np = np.asarray(g)
+                acc_np = np.asarray(n_acc)
+            self.stats["decode_steps"] += 1
+            self.stats["spec_ticks"] += 1
+            self.stats["draft_tokens"] += k * n_active
+            self.stats["accepted_draft_tokens"] += int(acc_np[active].sum())
+            self.stats["decode_slots"] += n_active
+            self.stats["decode_kv_rows"] += sum(clocks.values())
+            with TraceAnnotation("serve.sample"):
+                for i, t0 in clocks.items():
+                    # t0: committed write frontier BEFORE this tick's
+                    # emissions
+                    for j in range(int(acc_np[i]) + 1):
+                        if self.slots[i] is None:  # EOS/budget mid-window
+                            break
+                        self._emit(i, int(g_np[i, j]), finished)
+                        self.stats["decode_tokens"] += 1
+                    if self.paged is not None and self.slots[i] is not None:
+                        # clock-decrement rollback happened in-graph;
+                        # release any page now holding only rejected
+                        # rows.  The last committed row is t0 + n_acc
+                        # (keep = n_acc + 1 rows starting at t0).
+                        self.paged.rollback(i, t0 + int(acc_np[i]))
 
     # -- driver ------------------------------------------------------------
 
@@ -699,13 +784,14 @@ class Scheduler:
         speculating) over the pool.  Returns requests finished this
         tick."""
         finished: list[Completion] = []
-        self._admit(finished)
-        if self.chunk is not None:
-            self._ingest_tick(finished)
-        if self.spec_k:
-            self._spec_tick(finished)
-        else:
-            self._decode_active(finished)
+        with TraceAnnotation("serve.step"):
+            self._admit(finished)
+            if self.chunk is not None:
+                self._ingest_tick(finished)
+            if self.spec_k:
+                self._spec_tick(finished)
+            else:
+                self._decode_active(finished)
         self.step_count += 1
         return finished
 
