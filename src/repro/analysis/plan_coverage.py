@@ -18,7 +18,8 @@ The runtime shape mirror follows the scheduler contract:
   * decode ticks are width 1 at the full slot pool;
   * admits prefill at `ceil(maxlen / prefill_bucket) * prefill_bucket`
     capped at max_seq — so every bucket multiple up to max_seq is
-    reachable;
+    reachable, at the full slot pool (paged layout, chunks, the draft)
+    and, on the contiguous layout, at batch 1 (one call per pick);
   * a `speculate_k=k` server adds exactly the fused k+1 verify width;
   * a paged server adds the gather-attention shape spanning
     `slot_pages * page_size` addressable rows;
@@ -151,30 +152,34 @@ def expected_requests(cfg, surface: Surface):
         reqs.append((KernelRequest(dense_op, m, k, n, in_bytes=dense_in,
                                    out_bytes=out_b, density=density), label))
 
-    for width in sorted(set(widths)):
-        tokens = BATCH * width
+    shapes = [(BATCH, w) for w in sorted(set(widths))]
+    if surface.layout == "contiguous":
+        shapes += [(1, w) for w in admit_widths()]
+    for b, width in shapes:
+        tokens = b * width
+        tag = f"w={width}" + ("" if b == BATCH else f" b={b}")
         for kind in sorted(set(cfg.layer_pattern)):
             if kind in ("attn", "local"):
-                dense(tokens, d, nh * hd, f"{kind}/q w={width}")
-                dense(tokens, d, nkv * hd, f"{kind}/kv w={width}")
-                dense(tokens, nh * hd, d, f"{kind}/o w={width}")
+                dense(tokens, d, nh * hd, f"{kind}/q {tag}")
+                dense(tokens, d, nkv * hd, f"{kind}/kv {tag}")
+                dense(tokens, nh * hd, d, f"{kind}/o {tag}")
             elif kind == "rglru":
                 w = cfg.rglru_width or d
-                dense(tokens, d, w, f"rglru/in w={width}")
-                dense(tokens, w, w, f"rglru/gate w={width}")
-                dense(tokens, w, d, f"rglru/out w={width}")
+                dense(tokens, d, w, f"rglru/in {tag}")
+                dense(tokens, w, w, f"rglru/gate {tag}")
+                dense(tokens, w, d, f"rglru/out {tag}")
             elif kind == "ssm":
                 continue  # raw matmuls, not engine-routed
             if cfg.moe is not None:
-                rows = BATCH * cfg.moe.capacity(width)
+                rows = b * cfg.moe.capacity(width)
                 for m, k, n in ((rows, d, f), (rows, f, d)):
                     reqs.append((KernelRequest(
                         "grouped_gemm", m, k, n, groups=cfg.moe.n_experts,
                         in_bytes=plan_bytes, out_bytes=out_b),
-                        f"{kind}/expert w={width}"))
+                        f"{kind}/expert {tag}"))
             else:
-                dense(tokens, d, f, f"{kind}/ffn_up w={width}")
-                dense(tokens, f, d, f"{kind}/ffn_down w={width}")
+                dense(tokens, d, f, f"{kind}/ffn_up {tag}")
+                dense(tokens, f, d, f"{kind}/ffn_down {tag}")
     if surface.layout == "paged" and "attn" in cfg.layer_pattern:
         slot_pages = -(-MAX_SEQ // PAGE_SIZE)
         reqs.append((KernelRequest(

@@ -557,8 +557,10 @@ def plan_arch(cfg, *, seq_len: int | None = None, batch: int = 1,
     `decode_batch` additionally plans the fixed decode-step shapes for
     a slot pool of that size (see `decode_requests`) so a continuous-
     batching server's decode trace re-plans nothing; `admit_widths`
-    does the same for its ragged-prefill admit widths (the scheduler's
-    `prefill_bucket` multiples).  `quantized_weights` plans the decode/
+    does the same for its admit widths (the scheduler's
+    `prefill_bucket` multiples), at batch 1 (the contiguous layout's
+    per-pick prefill) and at the whole pool (the paged layout's ragged
+    prefill and the draft's).  `quantized_weights` plans the decode/
     admit dense projections as `gemm_w8` (a `quant.quantize_params`
     server dispatches those instead of `gemm`); `sparse_weights` plans
     them as `gemm_sparse` at `sparse_density` (a `sparse.prune_params`
@@ -593,8 +595,10 @@ def plan_arch(cfg, *, seq_len: int | None = None, batch: int = 1,
             widths = widths + (prefill_chunk,)
         if verify_k:
             widths = widths + (verify_k + 1,)
-        for width in widths:
-            for req in decode_requests(cfg, batch=decode_batch,
+        shapes = [(decode_batch, w) for w in widths] + \
+            [(1, w) for w in admit_widths]
+        for b, width in shapes:
+            for req in decode_requests(cfg, batch=b,
                                        dtype_bytes=in_bytes, seq=width,
                                        quantized_weights=quantized_weights,
                                        sparse_weights=sparse_weights,

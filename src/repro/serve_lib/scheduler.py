@@ -11,11 +11,15 @@ the model layer already supports it: `flash_attention` takes per-slot
 `Scheduler` owns a fixed pool of `ServeConfig.batch` slots over ONE
 persistent cache:
 
-  admit   queued requests enter free slots via a ragged prefill
-          (`transformer.prefill(lengths=..., update_mask=...)`): each
-          prompt is written at its slot with per-slot positions/clock,
-          in-flight slots untouched.  The first output token is sampled
-          from the prefill logits.
+  admit   queued requests enter free slots by prefill.  On the
+          contiguous cache each pick runs its own batch-1 prefill over
+          its slot alone, at its own bucketed width: the slot's leaves
+          are sliced out of the pool's cache, prefilled and written
+          back, in-flight slots untouched.  The paged cache (block
+          tables, shared-prefix history) and chunked ingestion keep one
+          ragged call over the whole pool
+          (`transformer.prefill(lengths=..., update_mask=...)`).  The
+          first output token is sampled from the prefill logits.
   decode  one fused `decode_step` over the whole pool with an `active`
           mask — the call shapes NEVER change, so the jitted step (and
           the `repro.engine` decision cache behind it) is reused for
@@ -26,8 +30,10 @@ persistent cache:
           recurrent state.
 
 Prefill is the only shape-variable call: prompt widths are rounded up
-to `prefill_bucket` (1 = exact group max — bitwise-parity mode; larger
-buckets bound jit retraces to O(max_seq / bucket) distinct widths).
+to `prefill_bucket` (1 = exact prompt length — bitwise-parity mode;
+larger buckets bound jit retraces to O(max_seq / bucket) distinct
+widths).  The slot index of the per-pick prefill is traced, so one
+program per width serves every slot and every number of picks.
 
 Chunked prefill (`ServeConfig.prefill_chunk`, DESIGN.md §12) bounds the
 other head-of-line blocker: without it, one long arriving prompt
@@ -42,10 +48,10 @@ decoupled from stepping.
 
 Greedy outputs match per-request `serve.generate` exactly for every
 cache kind; the one caveat is MoE capacity dropping: expert capacity
-scales with the CALL's padded width, so at drop-inducing capacity
-factors an MoE request's dropped tokens can depend on its admit
-group's width (DESIGN.md §6) — exactly the width dependence the
-static `generate` path already has versus `forward`.
+scales with the call's padded width, so at drop-inducing capacity
+factors an MoE request's dropped tokens can depend on its own bucketed
+width (or, on the paged layout, its admit group's) — the width
+dependence the static `generate` path already has versus `forward`.
 """
 
 from __future__ import annotations
@@ -136,6 +142,11 @@ def _jitted_steps(cfg: ArchConfig, scfg: serve_lib.ServeConfig, engine,
     same gathered-pages path shared prefixes use), so there the chunk
     step IS the admit step.
 
+    The contiguous prefill runs ONE slot: it takes the whole cache and
+    a traced slot index, slices that slot out of every leaf, prefills
+    it at batch 1 and writes it back.  One program per width serves
+    every slot; every other slot is untouched.
+
     Each jit wraps a named function, so its programs carry a stable
     name (`jit_serve_prefill`, ...) in compiler dumps and device
     traces."""
@@ -155,10 +166,27 @@ def _jitted_steps(cfg: ArchConfig, scfg: serve_lib.ServeConfig, engine,
         prefill = jax.jit(serve_prefill, static_argnames=("hist_pages",))
         return prefill, jax.jit(serve_decode), prefill
 
-    def serve_prefill(p, tok, cache, lens, mask):
-        return T.prefill(p, cfg, tok, cache,
-                         compute_dtype=scfg.compute_dtype, lengths=lens,
-                         update_mask=mask)
+    def serve_prefill(p, tok, cache, lens, i):
+        # slots leaves are (n_periods, B, ...); tail leaves and t (B, ...)
+        def slot(a, ax):
+            return jax.lax.dynamic_slice_in_dim(a, i, 1, ax)
+
+        def put(a, new, ax):
+            return jax.lax.dynamic_update_slice_in_dim(
+                a, new.astype(a.dtype), i, ax)
+
+        one = {"t": slot(cache["t"], 0),
+               "slots": jax.tree.map(lambda a: slot(a, 1), cache["slots"]),
+               "tail": jax.tree.map(lambda a: slot(a, 0), cache["tail"])}
+        logits, new = T.prefill(p, cfg, tok, one,
+                                compute_dtype=scfg.compute_dtype,
+                                lengths=lens)
+        return logits, {
+            "t": put(cache["t"], new["t"], 0),
+            "slots": jax.tree.map(lambda a, n: put(a, n, 1),
+                                  cache["slots"], new["slots"]),
+            "tail": jax.tree.map(lambda a, n: put(a, n, 0),
+                                 cache["tail"], new["tail"])}
 
     def serve_decode(p, cache, tok, act):
         return T.decode_step(p, cfg, cache, tok,
@@ -270,9 +298,10 @@ class Scheduler:
                       # adds its width once per admitted slot, so
                       # bucketing mixed-history admits by hist_pages
                       # shows up as a drop (PR 7).  It is not what the
-                      # device computes: that is prefill_rows, batch x
-                      # width per prefill or chunk call (every slot of
-                      # the pool, padding included)
+                      # device computes: that is prefill_rows, the rows
+                      # of every prefill or chunk call (width for a
+                      # per-pick call, batch x width for a whole-pool
+                      # one; padding included)
                       "prefill_tokens": 0, "prefill_width_sum": 0,
                       "prefill_rows": 0, "shared_prefix_tokens": 0,
                       # decode/verify calls: active slots, and their
@@ -492,20 +521,27 @@ class Scheduler:
         if self.spec_k and picks:
             self._draft_prefill(picks)
 
+    def _width(self, n: int) -> int:
+        """The prefill width of `n` tokens: rounded up to
+        `prefill_bucket`, capped at max_seq."""
+        return min(-(-n // self.prefill_bucket) * self.prefill_bucket,
+                   self.scfg.max_seq)
+
     def _prefill_group(self, picks: list[tuple[int, Request]],
                        hists: dict[int, int], hist_pages: int,
                        finished: list[Completion]) -> None:
-        """One ragged prefill call over `picks` (all sharing
-        `hist_pages` resident history pages); each admitted slot's first
-        output token comes from its last-token logits row (same
-        semantics as serve.generate)."""
+        """Prefill `picks` (all sharing `hist_pages` resident history
+        pages); each admitted slot's first output token comes from its
+        last-token logits row (same semantics as serve.generate)."""
+        if self.paged is None:
+            self._prefill_slots(picks, finished)
+            return
         b = self.scfg.batch
         # with a prefix-cache hit only the un-resident suffix prefills
-        maxlen = max(int(np.asarray(r.prompt).size) - hists.get(i, 0)
-                     for i, r in picks)
-        width = -(-maxlen // self.prefill_bucket) * self.prefill_bucket
-        width = min(width, self.scfg.max_seq)
-        with TraceAnnotation("serve.prefill", width=width, picks=len(picks)):
+        width = self._width(max(int(np.asarray(r.prompt).size)
+                                - hists.get(i, 0) for i, r in picks))
+        with TraceAnnotation("serve.prefill", width=width, picks=len(picks),
+                             rows=b * width):
             with TraceAnnotation("serve.stage"):
                 tokens = np.zeros((b, width), np.int32)
                 lengths = np.ones((b,), np.int32)
@@ -519,35 +555,75 @@ class Scheduler:
                     hist_arr[i] = hists.get(i, 0)
                     mask[i] = True
                 with self._scope():
-                    if self.paged is not None:
-                        logits, self.cache = self._prefill(
-                            self.params, jnp.asarray(tokens), self.cache,
-                            jnp.asarray(lengths), jnp.asarray(mask),
-                            jnp.asarray(self.paged.tables),
-                            jnp.asarray(hist_arr), hist_pages=hist_pages)
-                    else:
-                        logits, self.cache = self._prefill(
-                            self.params, jnp.asarray(tokens), self.cache,
-                            jnp.asarray(lengths), jnp.asarray(mask))
+                    logits, self.cache = self._prefill(
+                        self.params, jnp.asarray(tokens), self.cache,
+                        jnp.asarray(lengths), jnp.asarray(mask),
+                        jnp.asarray(self.paged.tables),
+                        jnp.asarray(hist_arr), hist_pages=hist_pages)
             with TraceAnnotation("serve.pull"):
                 rows = np.asarray(logits[:, -1], np.float32)
-            self.stats["prefill_calls"] += 1
-            self.stats["prefill_widths"].add(width)
-            self.stats["prefill_tokens"] += int(lengths[mask].sum())
-            self.stats["prefill_width_sum"] += width * len(picks)
-            self.stats["prefill_rows"] += b * width
-            if self.paged is not None:
-                # index the now-resident full prompt pages so later
-                # admissions with the same prefix reuse them (ingesting
-                # slots defer to their final chunk — the index must not
-                # advertise pages whose rows are not written yet)
-                for i, req in picks:
-                    self.paged.note_prefilled(
-                        i, np.asarray(req.prompt, np.int32).tolist())
+            self._count_prefill(width, int(lengths[mask].sum()), len(picks),
+                                b * width)
+            # index the now-resident full prompt pages so later
+            # admissions with the same prefix reuse them (ingesting
+            # slots defer to their final chunk — the index must not
+            # advertise pages whose rows are not written yet)
+            for i, req in picks:
+                self.paged.note_prefilled(
+                    i, np.asarray(req.prompt, np.int32).tolist())
             with TraceAnnotation("serve.sample"):
                 for i, _ in picks:
                     self._emit(i, self._sample(self.slots[i], rows[i]),
                                finished)
+
+    def _prefill_slots(self, picks: list[tuple[int, Request]],
+                       finished: list[Completion]) -> None:
+        """Contiguous layout: one batch-1 prefill per pick over its own
+        slot, at the pick's own bucketed width, so no row of another
+        slot and no wider prompt of the group is computed.  Every call
+        is dispatched (two in flight at most) before the one pull of
+        the picks' last-token rows, which sits with the sampling in the
+        last call's span."""
+        outs = []
+        for n, (i, req) in enumerate(picks):
+            prompt = np.asarray(req.prompt, np.int32).reshape(-1)
+            width = self._width(prompt.size)
+            with TraceAnnotation("serve.prefill", width=width, picks=1,
+                                 rows=width):
+                with TraceAnnotation("serve.stage"):
+                    tokens = np.zeros((1, width), np.int32)
+                    tokens[0, : prompt.size] = prompt
+                    if n >= 2:
+                        # two calls in flight at most: each holds a new
+                        # whole cache from its dispatch on, so a tick's
+                        # every pick at once would hold one per pick
+                        outs[n - 2].block_until_ready()
+                    with self._scope():
+                        logits, self.cache = self._prefill(
+                            self.params, jnp.asarray(tokens), self.cache,
+                            jnp.asarray([prompt.size], np.int32),
+                            np.int32(i))
+                outs.append(logits)
+                self._count_prefill(width, prompt.size, 1, width)
+                if n < len(picks) - 1:
+                    continue
+                with TraceAnnotation("serve.pull"):
+                    # (1, 1, V) each: the host converts, no device op
+                    rows = [np.asarray(x, np.float32)[0, -1]
+                            for x in jax.device_get(outs)]
+                with TraceAnnotation("serve.sample"):
+                    for (j, _), row in zip(picks, rows, strict=True):
+                        self._emit(j, self._sample(self.slots[j], row),
+                                   finished)
+
+    def _count_prefill(self, width: int, tokens: int, slots: int,
+                       rows: int) -> None:
+        st = self.stats
+        st["prefill_calls"] += 1
+        st["prefill_widths"].add(width)
+        st["prefill_tokens"] += tokens
+        st["prefill_width_sum"] += width * slots
+        st["prefill_rows"] += rows
 
     def _ingest_tick(self, finished: list[Completion]) -> None:
         """Advance every ingesting slot by one `prefill_chunk`-wide
@@ -577,7 +653,8 @@ class Scheduler:
         else:
             hp = 0
         b, ch = self.scfg.batch, self.chunk
-        with TraceAnnotation("serve.ingest", width=ch, slots=len(ing)):
+        with TraceAnnotation("serve.ingest", width=ch, slots=len(ing),
+                             rows=b * ch):
             with TraceAnnotation("serve.stage"):
                 tokens = np.zeros((b, ch), np.int32)
                 lengths = np.ones((b,), np.int32)
@@ -607,11 +684,7 @@ class Scheduler:
                             jnp.asarray(hist_arr))
             with TraceAnnotation("serve.pull"):
                 rows = np.asarray(logits[:, -1], np.float32)
-            self.stats["prefill_calls"] += 1
-            self.stats["prefill_widths"].add(ch)
-            self.stats["prefill_tokens"] += sum(takes.values())
-            self.stats["prefill_width_sum"] += ch * len(ing)
-            self.stats["prefill_rows"] += b * ch
+            self._count_prefill(ch, sum(takes.values()), len(ing), b * ch)
             done: list[tuple[int, Request]] = []
             for i, s in ing:
                 s.ingest_pos += takes[i]
@@ -639,9 +712,8 @@ class Scheduler:
         emitted token comes from the TARGET's prefill row, and the next
         spec tick feeds it back through `draft_propose`."""
         b = self.scfg.batch
-        maxlen = max(int(np.asarray(r.prompt).size) for _, r in picks)
-        width = -(-maxlen // self.prefill_bucket) * self.prefill_bucket
-        width = min(width, self.scfg.max_seq)
+        width = self._width(max(int(np.asarray(r.prompt).size)
+                                for _, r in picks))
         with TraceAnnotation("serve.draft_prefill", width=width,
                              picks=len(picks)), \
                 TraceAnnotation("serve.stage"):

@@ -396,7 +396,8 @@ def test_expected_requests_match_decode_requests_per_width():
     """The coverage pass's independent runtime-shape derivation agrees
     with `engine.decode_requests` (the thing `plan_arch` consumes) on
     every surface of the reference posture: same request set at decode
-    width 1 plus each admit width, per surface."""
+    width 1 plus each admit width, per surface (and each admit width at
+    batch 1 on the contiguous layout)."""
     from repro.engine.context import backend_in_bytes, decode_requests
 
     for cfg in all_configs().values():
@@ -409,9 +410,12 @@ def test_expected_requests_match_decode_requests_per_width():
             backend = PC.backend_for(surface)
             slot_pages = -(-PC.MAX_SEQ // PC.PAGE_SIZE)
             want = set()
-            for width in sorted(set(widths)):
+            shapes = [(PC.BATCH, w) for w in sorted(set(widths))]
+            if surface.layout == "contiguous":
+                shapes += [(1, w) for w in PC.admit_widths()]
+            for b, width in shapes:
                 for req in decode_requests(
-                        cfg, batch=PC.BATCH, seq=width,
+                        cfg, batch=b, seq=width,
                         dtype_bytes=backend_in_bytes(backend, 2),
                         out_bytes=2,  # plan_arch keeps the compute width
                         quantized_weights=surface.quantize,

@@ -255,6 +255,76 @@ def test_decode_inactive_slots_frozen(arch):
                                   np.asarray(logits_all[0]))
 
 
+@pytest.mark.parametrize("bucket", [1, 8])
+@pytest.mark.parametrize("arch", KINDS)
+def test_admit_group_leaves_inflight_slots_untouched(arch, bucket):
+    """Three picks of different bucketed widths admitted in one tick,
+    each by its own batch-1 prefill, next to two slots in flight: the
+    in-flight slots' cache leaves and clocks are bitwise unchanged by
+    the admission, and every request still emits exactly the tokens
+    per-request `generate` produces."""
+    cfg, params, scfg = _setup(arch, batch=5)
+    rng = np.random.default_rng(8)
+    reqs = [Request(uid=u, prompt=rng.integers(0, cfg.vocab, n)
+                    .astype(np.int32), max_new_tokens=g)
+            for u, (n, g) in enumerate([(6, 8), (11, 7), (5, 5), (13, 6),
+                                        (21, 4)])]
+    sched = Scheduler(params, cfg, scfg, prefill_bucket=bucket)
+    for r in reqs[:2]:
+        sched.submit(r)
+    sched.step()
+    prefills, decoded = [], []
+    prefill, decode = sched._prefill, sched._decode
+    sched._prefill = lambda *a: prefills.append(a[1].shape) or prefill(*a)
+    sched._decode = lambda p, c, *a: decoded.append(c) or decode(p, c, *a)
+    before = sched.cache
+    for r in reqs[2:]:
+        sched.submit(r)
+    sched.step()
+    assert len({w for _, w in prefills}) == 3
+    assert all(b == 1 for b, _ in prefills)
+    for i in (0, 1):
+        for a, b in zip(jax.tree.leaves(_slot_view(before, i)),
+                        jax.tree.leaves(_slot_view(decoded[0], i)),
+                        strict=True):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    comps = sched.run(max_steps=300)
+    scfg1 = dataclasses.replace(scfg, batch=1)
+    for r in reqs:
+        ref = serve_lib.generate(params, cfg, scfg1,
+                                 jnp.asarray(r.prompt)[None],
+                                 r.max_new_tokens)
+        np.testing.assert_array_equal(comps[r.uid].tokens, np.asarray(ref)[0],
+                                      err_msg=f"{arch} uid={r.uid}")
+
+
+def test_one_prefill_program_per_width():
+    """Admitting groups of 1 to 8 picks at every bucketed width traces
+    exactly one prefill program per width, whatever the slots and the
+    number of picks: the set of programs one single-pick admit per
+    width warms (as the benchmark's set-up does) is all a run needs."""
+    from repro.serve_lib import scheduler as scheduler_mod
+
+    cfg, params, scfg = _setup("qwen2-1.5b", batch=8, max_seq=32)
+    scheduler_mod._jitted_steps.cache_clear()
+    sched = Scheduler(params, cfg, scfg, prefill_bucket=8)
+    widths = (8, 16, 24, 32)
+    rng = np.random.default_rng(9)
+    uid = 0
+    for n in range(1, 9):
+        for w in widths:
+            for _ in range(n):
+                plen = int(rng.integers(w - 7, min(w, scfg.max_seq - 1) + 1))
+                sched.submit(Request(uid=uid, prompt=rng.integers(
+                    0, cfg.vocab, plen).astype(np.int32), max_new_tokens=1))
+                uid += 1
+            sched.step()
+            assert not sched.queue and not sched.n_active
+    assert sched.stats["prefill_calls"] == uid
+    assert sched.stats["prefill_widths"] == set(widths)
+    assert sched._prefill._cache_size() == len(widths)
+
+
 # --------------------------------------------------------------------------
 # Engine: the decode step's fixed shapes are fully covered by plan_arch
 # --------------------------------------------------------------------------

@@ -148,13 +148,15 @@ def _expected_decode(prompt_len: int, n_new: int, k: int) -> tuple[int, int]:
 def test_span_tree(served, posture):
     """Every tick is one serve.step holding the admit/ingest/decode/spec
     spans, and each of those the stage -> pull -> sample leaves, in
-    that order and properly nested; the spans' metadata agrees with the
-    counters."""
+    that order and properly nested (a per-pick prefill call holds its
+    stage alone, but the last of its admit, which holds the one pull
+    and sample); the spans' metadata agrees with the counters."""
     run = served(posture)
     assert len(run.steps) == run.sched.step_count
     assert all(s.name == "serve.step" for s in run.steps)
+    per_pick = posture != "paged"
     seen: set[str] = set()
-    widths = decode_slots = 0
+    rows = decode_slots = 0
     for step in run.steps:
         names = step.names()
         assert names, "a tick with no work"
@@ -171,6 +173,7 @@ def test_span_tree(served, posture):
                     "serve.prefill", "serve.draft_prefill"}
             else:
                 kids = [call]
+            prefills = [k for k in kids if k.name == "serve.prefill"]
             for k in kids:
                 seen.add(k.name)
                 got = [c.name for c in k.children
@@ -179,10 +182,18 @@ def test_span_tree(served, posture):
                     assert got == ["serve.stage"]
                 elif k.name == "serve.ingest" and len(got) == 2:
                     assert got == list(LEAVES[:2])  # no prompt completed
+                elif per_pick and k.name == "serve.prefill" \
+                        and k is not prefills[-1]:
+                    assert got == ["serve.stage"]   # pulled with the last
                 else:
                     assert got == list(LEAVES), (k.name, got)
+                if k.name == "serve.prefill":
+                    assert k.meta["rows"] == (
+                        k.meta["width"] if per_pick
+                        else BATCH * k.meta["width"]), k.meta
+                    assert k.meta["picks"] == 1 or not per_pick
                 if k.name in ("serve.prefill", "serve.ingest"):
-                    widths += k.meta["width"]
+                    rows += k.meta["rows"]
                 if k.name in ("serve.decode", "serve.spec"):
                     decode_slots += k.meta["slots"]
                 for leaf in k.children:
@@ -196,21 +207,30 @@ def test_span_tree(served, posture):
             "speculative": {"serve.spec", "serve.draft_prefill"}}[posture]
     assert want <= seen and not ({"serve.decode", "serve.spec"} - want) & seen
     st = run.sched.stats
-    assert BATCH * widths == st["prefill_rows"]
+    assert rows == st["prefill_rows"]
     assert decode_slots == st["decode_slots"]
 
 
 @pytest.mark.parametrize("posture", list(POSTURES))
 def test_batch_shape_counters(served, posture):
-    """prefill_rows is batch x width over every prefill and chunk call;
+    """prefill_rows is batch x width over every prefill and chunk call:
+    batch 1 for the single-shot prefill of the contiguous postures, the
+    whole pool for the paged prefill, chunks and the draft's prefill;
     decode_slots and decode_kv_rows follow from each request's prompt
     length and emitted count."""
     run = served(posture)
     st = run.sched.stats
-    shapes = [a[1].shape for attr in ("_prefill", "_chunk_prefill")
-              for a, _ in run.calls.get(attr, [])]
-    assert all(b == BATCH for b, _ in shapes)
-    assert st["prefill_rows"] == BATCH * sum(w for _, w in shapes)
+    shapes = {attr: [a[1].shape for a, _ in run.calls.get(attr, [])]
+              for attr in ("_prefill", "_chunk_prefill", "_dprefill")}
+    single = 1 if posture != "paged" else BATCH
+    assert shapes["_prefill"] and all(
+        b == single for b, _ in shapes["_prefill"])
+    assert all(b == BATCH for b, _ in shapes["_chunk_prefill"])
+    assert all(b == BATCH for b, _ in shapes["_dprefill"])
+    assert bool(shapes["_dprefill"]) == (posture == "speculative")
+    assert st["prefill_rows"] == sum(
+        b * w for attr in ("_prefill", "_chunk_prefill")
+        for b, w in shapes[attr])
     assert st["prefill_tokens"] == sum(p for p, _ in SHAPES)
     assert st["prefill_tokens"] < st["prefill_rows"]
     k = POSTURES[posture].get("speculate_k", 0)
